@@ -5,13 +5,12 @@ The GNN-aggregate section runs the BENCH_partition graph shapes through
 every layer formulation and writes **``BENCH_kernels.json``** (schema in
 BENCHMARKS.md):
 
-* **kernel vs kernel** (interpret mode, jitted): the fused
-  gather–normalize–matmul kernel against the unfused pair — the existing
-  ``gnn_gather_aggregate_pallas`` followed by the layer matmul. Interpret
-  mode is the only Pallas execution venue on this CPU-only box and both
-  arms pay the same interpreter, so the ratio isolates the structural
-  change (chunked slot gathers on a native-width slab vs the
-  slot-at-a-time ``fori_loop`` on a lane-padded slab).
+* **kernel vs kernel** (jitted): the fused gather–normalize–matmul
+  kernel against the unfused pair — the existing
+  ``gnn_gather_aggregate_pallas`` followed by the layer matmul. On a TPU
+  both run compiled (``impl="pallas"``; a kernel that does not compile
+  raises); on the CPU both run in the Pallas interpreter, where the ratio
+  only compares interpreter costs.
 * **XLA layer paths** (compiled wall-clock): fused/unfused gather layer
   vs the dense masked-SpMM layer.
 * **auto-selection**: ``resolve_aggregate`` on the real partition plan;
@@ -74,11 +73,13 @@ def _aggregate_record(n: int, e: int, rng: np.random.Generator) -> dict:
     ij, vj, dj = jnp.asarray(idx), jnp.asarray(val), jnp.asarray(dinv)
     cfg = get_config(n, n, FEATURE_DIM, FEATURE_DIM, k)
 
-    # kernel vs kernel (interpret mode, jitted — see module docstring)
+    # kernel vs kernel (jitted; interpret mode only on the CPU — see
+    # module docstring)
+    impl = "interpret" if jax.default_backend() == "cpu" else "pallas"
     fused_k = jax.jit(lambda xx: fused_gather_aggregate(
-        ij, vj, xx, dj, dj, w, impl="interpret"))
+        ij, vj, xx, dj, dj, w, impl=impl))
     unfused_k = jax.jit(lambda xx: gather_aggregate(
-        ij, vj, xx, dj, dj, impl="interpret") @ w)
+        ij, vj, xx, dj, dj, impl=impl) @ w)
     t_fused_k = _best_of(lambda: fused_k(x).block_until_ready())
     t_unfused_k = _best_of(lambda: unfused_k(x).block_until_ready())
 

@@ -503,57 +503,51 @@ def _failure_records(quick, mesh, devices) -> list:
     return records
 
 
-def _multihost_records(quick) -> list:
+def _multihost_records(quick, devices) -> list:
     """The multi-host SPMD arms (``"mode": "multihost"`` records).
 
-    Drives ``repro.launch.serve_multihost`` in subprocesses (each host
-    count needs its own ``jax.distributed`` world, so none of them can
-    share the bench's jax runtime): a replicate-everything single-process
-    **engine** baseline, then the **resident** sharded path at 1, 2 (and
-    ``--full`` 4) simulated hosts on the same ``community_graph`` —
-    10⁶ vertices under ``--full``, smoke-size under quick. hosts=1 writes
-    the reference output; every other arm must match it **bitwise**
-    (``parity_max_err == 0``, CI-gated), and halo bytes must stay
-    strictly under the replicate baseline's transfer."""
+    Drives ``repro.launch.serve_multihost`` over ``devices`` mesh devices:
+    a replicate-everything single-process **engine** baseline, then the
+    **resident** sharded path at 1, 2 (and ``--full`` 4) simulated hosts
+    on the same ``community_graph`` — 10⁶ vertices under ``--full``,
+    smoke-size under quick. The 1-process arms run in this process (a
+    process holding the chip cannot hand it to a child); the multi-host
+    arms spawn one ``jax.distributed`` worker per host, so they run only
+    on the CPU, where no chip is held. hosts=1 writes the reference output;
+    every other arm must match it **bitwise** (``parity_max_err == 0``,
+    CI-gated), and halo bytes must stay strictly under the replicate
+    baseline's transfer."""
     import json as _json
     import os
-    import pathlib
-    import subprocess
-    import sys
     import tempfile
 
-    n, e, steps = (20_000, 60_000, 3) if quick else (1_000_000, 3_000_000, 5)
-    devices = 4
-    host_counts = [1, 2] if quick else [1, 2, 4]
-    root = pathlib.Path(__file__).resolve().parent.parent
+    import jax
 
-    def launch(extra, hosts):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = str(root / "src") + (
-            os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={devices // hosts}"
-        with tempfile.TemporaryDirectory() as td:
-            out = os.path.join(td, "rec.json")
-            cmd = [sys.executable, "-m", "repro.launch.serve_multihost",
-                   "--processes", str(hosts), "--devices", str(devices),
-                   "--vertices", str(n), "--edges", str(e),
-                   "--steps", str(steps), "--json-out", out] + extra
-            proc = subprocess.run(cmd, env=env, cwd=root,
-                                  capture_output=True, text=True,
-                                  timeout=3600)
-            assert proc.returncode == 0, proc.stdout + proc.stderr
+    from repro.launch import serve_multihost
+
+    n, e, steps = (20_000, 60_000, 3) if quick else (1_000_000, 3_000_000, 5)
+    host_counts = [1]
+    if jax.default_backend() == "cpu":
+        host_counts += [h for h in ([2] if quick else [2, 4])
+                        if devices % h == 0]
+
+    with tempfile.TemporaryDirectory() as td:
+        ref, out = os.path.join(td, "ref.npy"), os.path.join(td, "rec.json")
+
+        def launch(extra, hosts):
+            rc = serve_multihost.main(
+                ["--processes", str(hosts), "--devices", str(devices),
+                 "--vertices", str(n), "--edges", str(e),
+                 "--steps", str(steps), "--json-out", out] + extra)
+            if rc != 0:
+                raise RuntimeError(f"serve_multihost {extra} failed ({rc})")
             with open(out) as f:
                 return _json.loads(f.read())
 
-    ref = tempfile.NamedTemporaryFile(suffix=".npy", delete=False)
-    ref.close()
-    try:
         eng = launch(["--arm", "engine", "--exchange", "gather"], 1)
         records = []
         for hosts in host_counts:
-            parity = ["--ref-out", ref.name] if hosts == 1 else \
-                ["--ref-in", ref.name]
+            parity = ["--ref-out", ref] if hosts == 1 else ["--ref-in", ref]
             rec = launch(["--arm", "resident"] + parity, hosts)
             rec["engine_steps_per_s"] = eng["steps_per_s"]
             rec["speedup_vs_engine"] = (rec["steps_per_s"]
@@ -565,14 +559,12 @@ def _multihost_records(quick) -> list:
                  f"speedup_vs_engine={rec['speedup_vs_engine']:.2f}x;"
                  f"halo_frac={rec['halo_frac']:.4f};"
                  f"parity_max_err={rec.get('parity_max_err', 0.0):.1e}")
-        eng["engine_steps_per_s"] = eng["steps_per_s"]
-        eng["speedup_vs_engine"] = 1.0
-        records.append(eng)
-        emit(f"multihost_engine_h1_n{n}", 1e6 / eng["steps_per_s"],
-             f"steps_per_s={eng['steps_per_s']:.2f};"
-             f"halo_frac={eng['halo_frac']:.4f}")
-    finally:
-        os.unlink(ref.name)
+    eng["engine_steps_per_s"] = eng["steps_per_s"]
+    eng["speedup_vs_engine"] = 1.0
+    records.append(eng)
+    emit(f"multihost_engine_h1_n{n}", 1e6 / eng["steps_per_s"],
+         f"steps_per_s={eng['steps_per_s']:.2f};"
+         f"halo_frac={eng['halo_frac']:.4f}")
     return records
 
 
@@ -661,12 +653,15 @@ def _run(quick: bool) -> None:
 
     records.extend(_streaming_records(quick, mesh, devices))
     records.extend(_failure_records(quick, mesh, devices))
-    records.extend(_multihost_records(quick))
+    records.extend(_multihost_records(quick, devices))
     write_bench_json(OUT_JSON, "serving", quick, records)
 
 
 if __name__ == "__main__":
     import argparse
+
+    from repro.launch.serve_gnn import _ensure_virtual_devices
+    _ensure_virtual_devices(4)     # 4 virtual CPU devices; no-op on a TPU
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--full", action="store_true",
                     help="paper-scale axes (slow)")

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels.gnn_aggregate.ops import (padded_neighbors_from_coo,
                                              rank_within_sorted_groups,
@@ -631,8 +630,8 @@ def _forward_blocks(mesh: Mesh, axis: str, aggregate: str, x_blocks,
                               a_args, ws_, agg_fn, axis)[None]
 
     specs_in = (P(axis),) * 7 + (P(),)       # agg_args sharded, ws replicated
-    fn = shard_map(device_fn, mesh=mesh, in_specs=specs_in,
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(device_fn, mesh=mesh, in_specs=specs_in,
+                       out_specs=P(axis), check_vma=False)
     return fn(x_blocks, send_idx, send_mask, dinv, cs_ext, mask, agg_args,
               ws)
 
@@ -661,8 +660,8 @@ def _forward_blocks_batched(mesh: Mesh, axis: str, aggregate: str, x_blocks,
         return jax.vmap(one)(x_bb)[None]
 
     specs_in = (P(axis),) * 7 + (P(),)
-    fn = shard_map(device_fn, mesh=mesh, in_specs=specs_in,
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(device_fn, mesh=mesh, in_specs=specs_in,
+                       out_specs=P(axis), check_vma=False)
     return fn(x_blocks, send_idx, send_mask, dinv, cs_ext, mask, agg_args,
               ws)
 
@@ -716,8 +715,8 @@ def _forward_blocks_multi(mesh: Mesh, axis: str, aggregate: str, x_blocks,
                              a_args)[None]
 
     specs_in = (P(axis),) * 7 + (P(),)
-    fn = shard_map(device_fn, mesh=mesh, in_specs=specs_in,
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(device_fn, mesh=mesh, in_specs=specs_in,
+                       out_specs=P(axis), check_vma=False)
     return fn(x_blocks, consts.send_idx, consts.send_mask, consts.dinv,
               consts.cs_ext, consts.mask, consts.agg_args, ws)
 

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-# jax<0.5 names this TPUCompilerParams; newer releases renamed it to CompilerParams
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def _ssd_kernel(x_ref, b_ref, c_ref, la_ref, y_ref, state_ref, *, l: int):
@@ -84,7 +82,7 @@ def ssd_chunk_scan_pallas(x, bmat, cmat, loga, *, chunk: int = 128,
                                lambda b_, h_, c_: (b_, c_, h_, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, bmat, cmat, loga)

@@ -5,9 +5,8 @@ The fused kernel (``fused.py``) is parameterized by a small config:
 * ``bm``  — rows per output tile (the gather width),
 * ``bf``  — feature columns per tile (both the XC slab slice and the
   matmul K-dim chunk share it, so one knob bounds the VMEM slab),
-* ``kc``  — neighbor slots gathered per inner step (the prefetch chunk of
-  the two-pass layout: gather ``[bm, kc]`` rows, then accumulate them
-  tile-locally before the next chunk lands).
+* ``kc``  — neighbor slots per inner step of each row's walk (the kernel
+  unrolls that many scalar-indexed row loads per loop iteration).
 
 Good choices depend on the *layout*, not the values: the padded slot
 count K (``max_degree``), the row/column counts, the feature widths and
@@ -33,6 +32,10 @@ from typing import Callable, NamedTuple
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
 _LANE = 128          # TPU lane width: feature blocks are multiples of this
 _SUBLANE = 8         # f32 sublane: row blocks are multiples of this
+# Scalar memory is 1 MiB per core on a v5e. The gather and fused kernels
+# keep their [bm, K] index and value blocks there, double-buffered, with K
+# padded to whole lanes; half of it is theirs.
+SMEM_BUDGET = 512 * 1024
 
 _DEFAULT_TABLE = pathlib.Path(__file__).resolve().parent / \
     "tuning_table.json"
@@ -65,8 +68,10 @@ def _round_down_pow2(x: int) -> int:
 
 def vmem_bytes(config: KernelConfig, n_cols: int, max_degree: int) -> int:
     """Resident VMEM of one fused tile: the ``[n_cols, bf]`` XC slab, the
-    ``[bm, K]`` index/value blocks, the ``[bf, bf]`` weight block, the
-    ``[bm, kc, bf]`` gather buffer and the ``[bm, bf]`` accumulator/out."""
+    ``[bm, K]`` index/value blocks, the ``[bf, bf]`` weight block, a
+    ``[bm, kc, bf]`` allowance for in-flight row loads (an over-count: the
+    kernel holds one ``[1, bf]`` row per load) and the ``[bm, bf]``
+    accumulator/out."""
     bm, bf, kc = config
     k = _round_up(max_degree, kc)
     return 4 * (n_cols * bf           # XC slab slice
@@ -74,6 +79,14 @@ def vmem_bytes(config: KernelConfig, n_cols: int, max_degree: int) -> int:
                 + bf * bf             # W block
                 + bm * kc * bf        # gathered chunk
                 + 2 * bm * bf)        # accumulator + out tile
+
+
+def smem_row_cap(max_degree: int) -> int:
+    """Most rows per tile whose index + value blocks (int32 + float32,
+    double-buffered, K padded to lanes) fit :data:`SMEM_BUDGET`; a multiple
+    of the sublane, at least one sublane."""
+    per_row = 2 * 2 * 4 * _round_up(max_degree, _LANE)
+    return max(_SUBLANE, SMEM_BUDGET // per_row // _SUBLANE * _SUBLANE)
 
 
 def heuristic_config(n_rows: int, n_cols: int, f_in: int, f_out: int,

@@ -11,17 +11,14 @@ matmul (the unfused path does exactly that round-trip). The row scale is
 applied on the accumulator before the matmul — linearity lets every
 normalization commute through the contraction.
 
-Layout: a *blocked two-pass* schedule replacing the slot-at-a-time
-``fori_loop`` of ``gnn_aggregate._gather_kernel``:
+Layout: a *blocked two-pass* schedule:
 
 * pass 1 (host, ops.py): neighbor slots are sorted by destination index
   with pads last (:func:`~repro.kernels.gnn_aggregate.ops.sort_neighbor_slots`),
-  so each tile's gathers walk the resident XC slab quasi-monotonically —
-  the prefetch-friendly order for Mosaic's dynamic-gather path;
-* pass 2 (kernel): each ``(bm, bf)`` tile gathers ``kc`` slots at a time
-  into a ``[bm, kc, bf]`` buffer and accumulates it tile-locally before
-  the next chunk lands, amortizing gather issue overhead ``kc``× over the
-  per-slot loop.
+  so each row's loads walk the resident XC slab in ascending order;
+* pass 2 (kernel): each ``(bm, bf)`` tile walks every row's slots ``kc``
+  at a time — scalar index and value from SMEM, one dynamic row load of
+  the slab each — into a VMEM accumulator, which then feeds the MXU.
 
 Grid = (N/bm, F_out/bf, F_in/bf); the F_in axis is the matmul reduction —
 o_ref accumulates across the innermost grid dimension (standard Pallas
@@ -40,33 +37,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; newer releases renamed it
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
 
-
-def _fused_kernel(idx_ref, val_ref, xc_ref, rs_ref, w_ref, o_ref, *,
-                  n_k: int, kc: int):
-    """One (bm, bf) output tile for one F_in chunk: chunked gather of the
-    row block's neighbor slots, tile-local weighted accumulate, row scale,
-    then the weight-block matmul accumulated into the output tile."""
+def _fused_kernel(idx_ref, val_ref, xc_ref, rs_ref, w_ref, o_ref, acc_ref,
+                  *, n_k: int, kc: int):
+    """One (bm, bf) output tile for one F_in chunk: per row, walk the
+    neighbor slots ``kc`` at a time (scalar index/value from SMEM, one
+    dynamic row load each) into the tile-local accumulator, apply the row
+    scale, then accumulate the weight-block matmul into the output tile."""
     l = pl.program_id(2)
 
     @pl.when(l == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    idx = idx_ref[...]
-    val = val_ref[...].astype(jnp.float32)
-    xc = xc_ref[...].astype(jnp.float32)
-    bm = idx.shape[0]
-    acc = jnp.zeros((bm, xc.shape[1]), jnp.float32)
-    for c in range(0, n_k, kc):                     # static: n_k % kc == 0
-        rows = jnp.take(xc, idx[:, c:c + kc].reshape(-1), axis=0)
-        rows = rows.reshape(bm, kc, xc.shape[1])
-        acc = acc + (rows * val[:, c:c + kc][:, :, None]).sum(axis=1)
-    acc = acc * rs_ref[...][:, None]
-    o_ref[...] += jnp.dot(acc, w_ref[...].astype(jnp.float32),
+    def row(r, carry):
+        def chunk(c, acc):
+            for t in range(kc):                     # static: n_k % kc == 0
+                s = c * kc + t
+                acc = acc + val_ref[r, s] * xc_ref[pl.ds(idx_ref[r, s], 1), :]
+            return acc
+
+        acc = jax.lax.fori_loop(0, n_k // kc, chunk,
+                                jnp.zeros((1, acc_ref.shape[1]), jnp.float32))
+        acc_ref[pl.ds(r, 1), :] = acc * rs_ref[pl.ds(r, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, acc_ref.shape[0], row, 0)
+    o_ref[...] += jnp.dot(acc_ref[...], w_ref[...].astype(jnp.float32),
                           preferred_element_type=jnp.float32)
 
 
@@ -94,18 +91,23 @@ def gnn_fused_aggregate_pallas(nbr_idx: jnp.ndarray, nbr_val: jnp.ndarray,
         functools.partial(_fused_kernel, n_k=k, kc=kc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j, l: (i, 0)),
-            pl.BlockSpec((bm, k), lambda i, j, l: (i, 0)),
-            pl.BlockSpec((n_cols, bf), lambda i, j, l: (0, l)),
-            pl.BlockSpec((bm,), lambda i, j, l: (i,)),
+            pl.BlockSpec((bm, k), lambda i, j, l: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((bm, k), lambda i, j, l: (i, 0),
+                         memory_space=pltpu.SMEM),
+            # one buffer: two of a PubMed-size slab overflow scoped VMEM
+            pl.BlockSpec((n_cols, bf), lambda i, j, l: (0, l),
+                         pipeline_mode=pl.Buffered(1)),
+            pl.BlockSpec((bm, 1), lambda i, j, l: (i, 0)),
             pl.BlockSpec((bf, bf), lambda i, j, l: (l, j)),
         ],
         out_specs=pl.BlockSpec((bm, bf), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, f_out), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(nbr_idx.astype(jnp.int32), nbr_val.astype(jnp.float32), xc,
-      jnp.broadcast_to(row_scale, (n,)).astype(jnp.float32),
+      jnp.broadcast_to(row_scale, (n,)).astype(jnp.float32)[:, None],
       w.astype(jnp.float32))
     return out

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.gnn_aggregate.autotune import (DEFAULT_VMEM_BUDGET,
-                                                  get_config, vmem_bytes)
+                                                  get_config, smem_row_cap,
+                                                  vmem_bytes)
 from repro.kernels.gnn_aggregate.fused import gnn_fused_aggregate_pallas
 from repro.kernels.gnn_aggregate.gnn_aggregate import (
     gnn_aggregate_pallas, gnn_gather_aggregate_pallas)
@@ -165,18 +166,19 @@ def gather_aggregate(nbr_idx: jnp.ndarray, nbr_val: jnp.ndarray,
     f = x.shape[1]
     budget = DEFAULT_VMEM_BUDGET if vmem_budget is None else int(vmem_budget)
     bf = gather_block_columns(x.shape[0], k, block, budget)
+    bm = min(block, smem_row_cap(k))
     cs = jnp.broadcast_to(jnp.asarray(col_scale, jnp.float32),
                           (x.shape[0],))
     xc = x.astype(jnp.float32) * cs[:, None]
     rs = jnp.broadcast_to(jnp.asarray(row_scale, jnp.float32), (n,))
     # pad rows of the neighbor lists and features of xc; pad rows of xc are
     # never indexed (indices stay < x.shape[0]) so only F needs padding there
-    idx_p = _pad_to(jnp.asarray(nbr_idx), block, (0,))
-    val_p = _pad_to(jnp.asarray(nbr_val), block, (0,))
-    rs_p = _pad_to(rs, block, (0,))
+    idx_p = _pad_to(jnp.asarray(nbr_idx), bm, (0,))
+    val_p = _pad_to(jnp.asarray(nbr_val), bm, (0,))
+    rs_p = _pad_to(rs, bm, (0,))
     xc_p = _pad_to(xc, bf, (1,))
     y = gnn_gather_aggregate_pallas(idx_p, val_p, xc_p, rs_p,
-                                    bm=block, bf=bf,
+                                    bm=bm, bf=bf,
                                     interpret=(impl == "interpret"))
     return y[:n, :f].astype(x.dtype)
 
@@ -214,6 +216,7 @@ def fused_gather_aggregate(nbr_idx: jnp.ndarray, nbr_val: jnp.ndarray,
             f"{vmem_bytes(config, n_cols, k)} B resident for n_cols="
             f"{n_cols}, K={k}, over the {budget} B VMEM budget")
     bm, bf, kc = config
+    bm = min(bm, smem_row_cap(k))
     cs = jnp.broadcast_to(jnp.asarray(col_scale, jnp.float32), (n_cols,))
     xc = x.astype(jnp.float32) * cs[:, None]
     rs = jnp.broadcast_to(jnp.asarray(row_scale, jnp.float32), (n,))

@@ -27,4 +27,29 @@ DRLGO (offloading-policy) training is not a launcher — use
 ``examples/train_drlgo.py`` (``--batch B`` for the vmapped batched
 environment) or drive :class:`repro.core.offload.drlgo.DRLGOTrainer`
 directly. See README.md for the repo-level map.
+
+:func:`enable_compile_cache` is the one place that points JAX's persistent
+compilation cache at a directory; the GraphEdge launchers and
+``chip_smoke.py`` call it before their first compile.
 """
+from __future__ import annotations
+
+import os
+import pathlib
+
+# <repo>/.jax_cache — a fixed path, because the path is part of the cache key
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    nothing is overridden. Otherwise the cache lives in
+    :data:`COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -36,6 +36,9 @@ identical).
 
 Importing this module has no side effects; env mutation happens inside
 worker ``main`` before jax is imported (same contract as ``serve_gnn``).
+With ``--processes 1`` nothing is spawned: ``main(argv)`` runs the worker
+in the caller's process, so a process that already holds the chips can
+call it directly.
 """
 from __future__ import annotations
 
@@ -153,6 +156,8 @@ def _worker(args: argparse.Namespace) -> int:
     if nproc > 1:
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(args.coordinator, nproc, pid)
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     import numpy as np
     from jax.sharding import Mesh
 
@@ -218,6 +223,7 @@ def _worker(args: argparse.Namespace) -> int:
         "devices": args.devices, "n": n, "edges": int(len(edges)),
         "exchange": args.exchange, "block": int(block), "halo": int(halo),
         "steps": args.steps, "steps_per_s": args.steps / dt,
+        "output_devices": len(out.sharding.device_set),
         "plan_build_s": plan_s,
         "halo_bytes_per_step": sum(pb(w) for w in layer_widths),
         "replicate_bytes_per_step": sum(rb(w) for w in layer_widths),

@@ -3,7 +3,7 @@ incremental env cost vs the batch model."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import costs
 from repro.core.dynamic_graph import make_graph_state, random_scenario

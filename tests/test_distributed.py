@@ -84,7 +84,9 @@ def test_reduced_mesh_dryrun_lowers():
                                             activation_shard_ctx)
         from repro.launch.shapes import params_specs
         from repro.optim.adamw import AdamWConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = reduced(get_config("qwen3-0.6b"), d_model=128, d_ff=256,
                       vocab=512)
         p_sds = jax.eval_shape(lambda: T.init_params(cfg,

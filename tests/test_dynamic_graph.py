@@ -6,7 +6,7 @@ symmetric with a zero diagonal, inactive rows/columns carry no edges, and
 import jax.numpy as jnp
 import numpy as np
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.core.dynamic_graph import (EVENT_ARRIVE, EVENT_DEPART, GraphEvent,
                                       GraphState, add_users, apply_user_event,
                                       arrival_wave, departure_wave,
@@ -107,7 +107,7 @@ def _assert_layout_invariants(state: GraphState) -> None:
     assert float(state.num_active()) == mask.sum()
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.sampled_from([0.1, 0.3, 0.6]))
 def test_property_perturb_preserves_invariants(seed, rate):
     rng = np.random.default_rng(seed)
@@ -117,7 +117,7 @@ def test_property_perturb_preserves_invariants(seed, rate):
         _assert_layout_invariants(state)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(1, 12))
 def test_property_arrival_wave_counts_and_invariants(seed, count):
     rng = np.random.default_rng(seed)
@@ -131,7 +131,7 @@ def test_property_arrival_wave_counts_and_invariants(seed, count):
     assert np.all(np.asarray(grown.mask) >= np.asarray(state.mask))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(1, 12))
 def test_property_departure_wave_counts_and_invariants(seed, count):
     rng = np.random.default_rng(seed)
@@ -143,7 +143,7 @@ def test_property_departure_wave_counts_and_invariants(seed, count):
     assert np.all(np.asarray(shrunk.mask) <= np.asarray(state.mask))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(0, 2**16))
 def test_property_add_users_arbitrary_adjacency(seed, adj_seed):
     """``add_users`` must sanitize an *arbitrary* (asymmetric, self-looped,
@@ -164,7 +164,7 @@ def test_property_add_users_arbitrary_adjacency(seed, adj_seed):
     assert int(np.asarray(grown.mask).sum()) == int(mask.sum() + add.sum())
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.integers(0, 2**16))
 def test_property_remove_users_subset(seed, drop_seed):
     rng = np.random.default_rng(seed)
@@ -178,7 +178,7 @@ def test_property_remove_users_subset(seed, drop_seed):
         int(np.asarray(state.mask).sum()) - int(gone.sum())
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1), st.sampled_from([EVENT_ARRIVE,
                                                    EVENT_DEPART]),
        st.integers(1, 6))

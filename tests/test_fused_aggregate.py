@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.data.graphs import random_graph
 from repro.gnn.distributed import (DENSE_AUTO_SLOT_RATIO, _forward_blocks,
                                    distributed_gcn_forward, make_forward_fn,

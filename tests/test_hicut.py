@@ -3,7 +3,7 @@ paper's Fig. 3 worked example."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from conftest import random_edges
 from repro.core.hicut import cut_metrics, hicut_jax, hicut_ref

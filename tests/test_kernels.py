@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hyp import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels.chunk_scan.ops import ssd_chunk_scan
 from repro.kernels.flash_attention.ops import flash_attention

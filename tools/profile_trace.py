@@ -5,7 +5,7 @@ Writes a TensorBoard-loadable trace directory (``xplane.pb`` under
 
 * ``fused_aggregate`` — the fused gather–normalize–matmul kernel vs the
   unfused gather-kernel + matmul pair on the BENCH_kernels n=5000 shape
-  (interpret mode, jitted — the kernel-vs-kernel comparison venue);
+  (jitted; compiled on a TPU, interpret mode on the CPU);
 * ``kernels``        — the whole ``benchmarks/bench_kernels.py`` quick run;
 * ``serving``        — the whole ``benchmarks/bench_serving.py`` quick run.
 
@@ -17,8 +17,8 @@ Usage (from the repo root)::
 The per-bench ``--profile DIR`` flags on ``benchmarks/bench_kernels.py``
 and ``benchmarks/bench_serving.py`` capture the same traces without this
 wrapper. Load the output with ``tensorboard --logdir DIR`` (or
-``xprof``); on this CPU-only box the trace shows XLA/interpreter op
-spans, on TPU the same lane captures device timelines.
+``xprof``); on the CPU the trace shows XLA/interpreter op spans, on a
+TPU the same lane captures device timelines.
 """
 from __future__ import annotations
 
@@ -51,10 +51,11 @@ def _trace_fused_aggregate(out: str) -> None:
     x = jnp.asarray(rng.normal(size=(n, f)).astype(np.float32))
     w = jnp.asarray(rng.normal(size=(f, f)).astype(np.float32) * 0.1)
     ij, vj, dj = jnp.asarray(idx), jnp.asarray(val), jnp.asarray(dinv)
+    impl = "interpret" if jax.default_backend() == "cpu" else "pallas"
     fused = jax.jit(lambda xx: fused_gather_aggregate(
-        ij, vj, xx, dj, dj, w, impl="interpret"))
+        ij, vj, xx, dj, dj, w, impl=impl))
     unfused = jax.jit(lambda xx: gather_aggregate(
-        ij, vj, xx, dj, dj, impl="interpret") @ w)
+        ij, vj, xx, dj, dj, impl=impl) @ w)
     fused(x).block_until_ready()        # compile outside the trace
     unfused(x).block_until_ready()
     with jax.profiler.trace(out):
