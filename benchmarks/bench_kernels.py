@@ -18,9 +18,7 @@ BENCHMARKS.md):
   (exactly 1.0 by construction when "dense" is selected — the selected
   arm *is* the dense timing then).
 
-``--profile`` wraps the timed section in a ``jax.profiler`` trace (one
-TensorBoard-loadable directory per run; see tools/profile_trace.py for
-the standalone lane). ``--quick`` / ``--full`` pick the axis sizes.
+``--quick`` / ``--full`` pick the axis sizes.
 """
 from __future__ import annotations
 
@@ -126,18 +124,7 @@ def _aggregate_record(n: int, e: int, rng: np.random.Generator) -> dict:
     return rec
 
 
-def run(quick: bool = True, profile_dir: str | None = None) -> None:
-    if profile_dir is not None:
-        jax.profiler.start_trace(profile_dir)
-    try:
-        _run(quick)
-    finally:
-        if profile_dir is not None:
-            jax.profiler.stop_trace()
-            print(f"# profile trace written to {profile_dir}")
-
-
-def _run(quick: bool) -> None:
+def run(quick: bool = True) -> None:
     rng = np.random.default_rng(0)
 
     cases = [(1_000, 10_000), (5_000, 50_000)] if quick else \
@@ -176,7 +163,5 @@ if __name__ == "__main__":
                     help="paper-scale axes (slow)")
     ap.add_argument("--quick", action="store_true",
                     help="small axes (the default; --full overrides)")
-    ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="write a jax.profiler trace of the run to DIR")
     args = ap.parse_args()
-    run(quick=not args.full, profile_dir=args.profile)
+    run(quick=not args.full)

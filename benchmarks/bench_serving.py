@@ -568,20 +568,7 @@ def _multihost_records(quick, devices) -> list:
     return records
 
 
-def run(quick: bool = True, profile_dir: str | None = None) -> None:
-    import jax
-
-    if profile_dir is not None:
-        jax.profiler.start_trace(profile_dir)
-    try:
-        _run(quick)
-    finally:
-        if profile_dir is not None:
-            jax.profiler.stop_trace()
-            print(f"# profile trace written to {profile_dir}")
-
-
-def _run(quick: bool) -> None:
+def run(quick: bool = True) -> None:
     import jax
     from jax.sharding import Mesh
 
@@ -667,7 +654,5 @@ if __name__ == "__main__":
                     help="paper-scale axes (slow)")
     ap.add_argument("--quick", action="store_true",
                     help="small axes (the default; --full overrides)")
-    ap.add_argument("--profile", metavar="DIR", default=None,
-                    help="write a jax.profiler trace of the run to DIR")
     args = ap.parse_args()
-    run(quick=not args.full, profile_dir=args.profile)
+    run(quick=not args.full)

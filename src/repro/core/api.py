@@ -57,6 +57,7 @@ from typing import Any, Callable, NamedTuple, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import costs
 from repro.core.dynamic_graph import GraphState, perturb_scenario
@@ -433,14 +434,22 @@ def _jit_decide(decide, net: costs.EdgeNetwork, state: GraphState, subgraph,
                 m: int) -> tuple[Assignment, costs.SystemCost]:
     """Run the jitted hot path and package the standard episode stats —
     the one place the (assignment, stats, cost) post-processing lives for
-    both the ``__call__(env)`` surface and ``GraphEdgeController.step``."""
-    assign, reward, sc = _jit_offload_and_cost(
-        net, state, jnp.asarray(subgraph, jnp.int32), zeta_sp, sub_w,
-        cost_scale, gnn, decide, m)
-    stats = {"reward": float(reward), "system_cost": float(sc.c),
-             "t_all": float(sc.t_all), "i_all": float(sc.i_all),
-             "cross_bits": float(sc.cross_bits.sum())}
-    return Assignment(np.asarray(assign, np.int64), float(reward), stats), sc
+    both the ``__call__(env)`` surface and ``GraphEdgeController.step``.
+    Profiler spans: ``ge.control.dispatch`` (the call and its argument
+    copies), ``ge.control.wait`` (the first host read, which waits for the
+    device), ``ge.control.unpack`` (the remaining reads)."""
+    with TraceAnnotation("ge.control.dispatch", batch=1):
+        assign, reward, sc = _jit_offload_and_cost(
+            net, state, jnp.asarray(subgraph, jnp.int32), zeta_sp, sub_w,
+            cost_scale, gnn, decide, m)
+    with TraceAnnotation("ge.control.wait"):
+        first = float(reward)
+    with TraceAnnotation("ge.control.unpack"):
+        stats = {"reward": first, "system_cost": float(sc.c),
+                 "t_all": float(sc.t_all), "i_all": float(sc.i_all),
+                 "cross_bits": float(sc.cross_bits.sum())}
+        return (Assignment(np.asarray(assign, np.int64), float(reward),
+                           stats), sc)
 
 
 def _jit_policy_call(policy: JitPolicy, env: OffloadEnv) -> Assignment:
@@ -670,15 +679,19 @@ class GraphEdgeController:
     # -- perceive + partition (cached on topology) --------------------------
     def _partition_cached(self, state: GraphState
                           ) -> tuple[Partition, str | None]:
-        """(partition, topology key) — key is None when caching is off."""
-        if not self.cache_partitions:
-            return self.partitioner(state), None
-        key = topology_key(state)
-        part = self._partition_cache.get(key)
-        if part is None:
-            part = self.partitioner(state)
-            self._partition_cache.put(key, part)
-        return part, key
+        """(partition, topology key) — key is None when caching is off.
+        The ``ge.control.partition`` profiler span, with stat ``hit``."""
+        with TraceAnnotation("ge.control.partition") as span:
+            if not self.cache_partitions:
+                span.set_metadata(hit=0)
+                return self.partitioner(state), None
+            key = topology_key(state)
+            part = self._partition_cache.get(key)
+            span.set_metadata(hit=int(part is not None))
+            if part is None:
+                part = self.partitioner(state)
+                self._partition_cache.put(key, part)
+            return part, key
 
     def partition(self, state: GraphState) -> Partition:
         """Run (or reuse) the partitioner. The cut depends only on the
@@ -795,27 +808,33 @@ class GraphEdgeController:
             return [self.step(s) for s in states]
         looked_up = [self._partition_cached(s) for s in states]
         parts = [p for p, _ in looked_up]
-        subs = jnp.asarray(np.stack([np.asarray(p.subgraph, np.int32)
-                                     for p in parts]))
-        assign_b, reward_b, sc_b = _jit_offload_and_cost_batch(
-            self.net, stack_states(list(states)), subs, self.zeta_sp,
-            1.0 if self.use_subgraph_reward else 0.0, self.cost_scale,
-            self.gnn, type(self.policy).decide,
-            int(self.net.server_pos.shape[0]))
+        with TraceAnnotation("ge.control.dispatch", batch=len(states)):
+            subs = jnp.asarray(np.stack([np.asarray(p.subgraph, np.int32)
+                                         for p in parts]))
+            assign_b, reward_b, sc_b = _jit_offload_and_cost_batch(
+                self.net, stack_states(list(states)), subs, self.zeta_sp,
+                1.0 if self.use_subgraph_reward else 0.0, self.cost_scale,
+                self.gnn, type(self.policy).decide,
+                int(self.net.server_pos.shape[0]))
         # one host fetch for the whole batch, then pure numpy unpacking
-        assign_np = np.asarray(assign_b, np.int64)
-        reward_np = np.asarray(reward_b, np.float64)
-        sc_np = jax.tree_util.tree_map(np.asarray, sc_b)
-        decisions = []
-        for b, (state, (part, key)) in enumerate(zip(states, looked_up)):
-            sc = jax.tree_util.tree_map(lambda leaf: leaf[b], sc_np)
-            stats = {"reward": float(reward_np[b]),
-                     "system_cost": float(sc.c), "t_all": float(sc.t_all),
-                     "i_all": float(sc.i_all),
-                     "cross_bits": float(sc.cross_bits.sum())}
-            assignment = Assignment(assign_np[b], float(reward_np[b]), stats)
-            decisions.append(Decision(state, part, assignment, sc,
-                                      topo_key=key))
+        with TraceAnnotation("ge.control.wait"):
+            assign_np = np.asarray(assign_b, np.int64)
+        with TraceAnnotation("ge.control.unpack"):
+            reward_np = np.asarray(reward_b, np.float64)
+            sc_np = jax.tree_util.tree_map(np.asarray, sc_b)
+            decisions = []
+            for b, (state, (part, key)) in enumerate(zip(states,
+                                                         looked_up)):
+                sc = jax.tree_util.tree_map(lambda leaf: leaf[b], sc_np)
+                stats = {"reward": float(reward_np[b]),
+                         "system_cost": float(sc.c),
+                         "t_all": float(sc.t_all),
+                         "i_all": float(sc.i_all),
+                         "cross_bits": float(sc.cross_bits.sum())}
+                assignment = Assignment(assign_np[b], float(reward_np[b]),
+                                        stats)
+                decisions.append(Decision(state, part, assignment, sc,
+                                          topo_key=key))
         return decisions
 
     def jit_step_batch_fn(self) -> Callable[[GraphState], JitStepResult]:

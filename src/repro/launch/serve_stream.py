@@ -216,6 +216,11 @@ def main(argv=None) -> dict:
         print(f"cycles={cyc['cycles']} batch_hist={cyc['batch_hist']} "
               f"decide p50={cyc['decide']['p50'] * 1e3:.2f}ms "
               f"p95={cyc['decide']['p95'] * 1e3:.2f}ms")
+        slow = cyc["slowest"][0]
+        print(f"slowest cycle {slow['cycle']} (batch {slow['batch']}): "
+              f"decide={slow['decide'] * 1e3:.2f}ms "
+              f"dispatch={slow['dispatch'] * 1e3:.2f}ms "
+              f"fetch={slow['fetch'] * 1e3:.2f}ms")
     if summary.get("served"):
         print(f"sustained {summary['sustained_rps']:.2f} req/s")
         for phase in ("queue_wait", "decide", "forward", "total"):

@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Iterable, Iterator, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.api import (CacheInfo, Decision, GraphEdgeController,
@@ -173,17 +174,23 @@ class ServingEngine:
         the plan and its jitted forward. The network digest rotates on
         :meth:`swap_network`, so entries priced under a stale network
         (pre-fault capacities) can never be served again — see the
-        regression test in ``tests/test_faults.py``."""
-        topo = decision.topo_key or topology_key(decision.state)
-        key = (topo, _assignment_digest(decision.servers), self._net_key)
-        hit = self._plan_cache.get(key)
+        regression test in ``tests/test_faults.py``. Profiler spans:
+        ``ge.plan.lookup`` (stat ``hit``), then on a miss
+        ``ge.plan.build``."""
+        with TraceAnnotation("ge.plan.lookup") as span:
+            topo = decision.topo_key or topology_key(decision.state)
+            key = (topo, _assignment_digest(decision.servers), self._net_key)
+            hit = self._plan_cache.get(key)
+            span.set_metadata(hit=int(hit is not None))
         if hit is not None:
             return hit, True
-        plan = decision.to_partition_plan(self.num_devices,
-                                          exchange=self.exchange)
-        forward = make_forward_fn(self.mesh, self.axis, plan, self.aggregate)
-        entry = PlanEntry(key, plan, forward)
-        self._plan_cache.put(key, entry)
+        with TraceAnnotation("ge.plan.build"):
+            plan = decision.to_partition_plan(self.num_devices,
+                                              exchange=self.exchange)
+            forward = make_forward_fn(self.mesh, self.axis, plan,
+                                      self.aggregate)
+            entry = PlanEntry(key, plan, forward)
+            self._plan_cache.put(key, entry)
         return entry, False
 
     def decide_entry(self, state: GraphState
@@ -201,9 +208,11 @@ class ServingEngine:
         — scene build + policy + exact cost stacked over the batch), then
         each decision goes through the plan LRU. This is the batched-decide
         hot path of the streaming front-end's pump loop; per-request decide
-        pays one dispatch per request, this pays one per cycle."""
-        decisions = self.controller.step_batch(states)
-        return [(d,) + self._plan_for(d) for d in decisions]
+        pays one dispatch per request, this pays one per cycle. The
+        ``ge.control.decide`` profiler span (stat ``batch``)."""
+        with TraceAnnotation("ge.control.decide", batch=len(states)):
+            decisions = self.controller.step_batch(states)
+            return [(d,) + self._plan_for(d) for d in decisions]
 
     def decide(self, state: GraphState
                ) -> tuple[Decision, PartitionPlan, Callable, bool]:
@@ -273,7 +282,9 @@ class ServingEngine:
         stacked closure is LRU-cached on the ordered member keys: steady
         streams cycling over a hot set of topologies rebuild nothing, and
         the jit cache underneath keys on the bucket shapes, so even a cold
-        member set of a warm bucket skips compilation."""
+        member set of a warm bucket skips compilation. A miss (padding,
+        constants, their stacking) is the ``ge.plan.stack`` profiler span
+        (stat ``members``)."""
         bucket = self.entry_bucket(entries[0])
         assert all(self.entry_bucket(e) == bucket for e in entries), \
             [self.entry_bucket(e) for e in entries]
@@ -281,12 +292,13 @@ class ServingEngine:
         hit = self._multi_cache.get(key)
         if hit is not None:
             return hit
-        members = [self._padded_member(e, bucket) for e in entries]
-        plans = [m[0] for m in members]
-        agg = entries[0].padded[bucket][2]
-        forward = make_multi_forward_fn(self.mesh, self.axis, agg,
-                                        [m[1] for m in members])
-        self._multi_cache.put(key, (plans, forward))
+        with TraceAnnotation("ge.plan.stack", members=len(entries)):
+            members = [self._padded_member(e, bucket) for e in entries]
+            plans = [m[0] for m in members]
+            agg = entries[0].padded[bucket][2]
+            forward = make_multi_forward_fn(self.mesh, self.axis, agg,
+                                            [m[1] for m in members])
+            self._multi_cache.put(key, (plans, forward))
         return plans, forward
 
     # -- network swap (fault migration) --------------------------------------

@@ -55,6 +55,7 @@ from dataclasses import dataclass, field
 from typing import Iterable, Iterator, Protocol, runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.api import LruCache, topology_key
 from repro.core.dynamic_graph import GraphState
@@ -556,8 +557,10 @@ class StreamingFrontend:
         ``max_batch``, whatever its topology. The whole batch is then
         decided in ONE vmapped controller call and dispatched per
         plan/bucket group (:meth:`_serve_cycle`). Returns the served
-        results of this cycle (possibly [])."""
-        with self._lock:
+        results of this cycle (possibly []). A non-empty cycle is the
+        ``ge.frontend.cycle`` profiler span (its stats: ``cycle``,
+        ``batch``, ``topologies``, ``groups``)."""
+        with TraceAnnotation("ge.frontend.admit"), self._lock:
             now = self.clock.now()
             self._poll_faults()       # pump boundary: nothing in flight
             backlog = len(self.queue)
@@ -594,27 +597,34 @@ class StreamingFrontend:
             self.admission.on_cycle(0, now)
             self._cycle += 1
             return []
-        results = self._serve_cycle(batch)
-        self.admission.on_cycle(len(batch), self.clock.now())
+        with TraceAnnotation("ge.frontend.cycle", cycle=self._cycle,
+                             batch=len(batch)) as span:
+            results = self._serve_cycle(batch, span)
+            self.admission.on_cycle(len(batch), self.clock.now())
         if results:
             self._mark_recovered()
         self._cycle += 1
         return results
 
-    def _serve_cycle(self, batch: list[_Entry]) -> list[StreamResult]:
+    def _serve_cycle(self, batch: list[_Entry], span: TraceAnnotation
+                     ) -> list[StreamResult]:
         """Serve one admitted cycle: ONE vmapped control decision over the
         cycle's unique topologies (:meth:`ServingEngine.decide_entries`),
         then one asynchronous dispatch per plan group — same-plan groups
         through the plan's batched forward, mixed groups sharing a shape
         bucket through the multi-plan cross-topology forward — and only
         then the blocking output fetches, so every group's device work
-        overlaps the others' host-side prep."""
+        overlaps the others' host-side prep. Each host↔device crossing is
+        its own profiler span: ``ge.transfer.scatter``,
+        ``ge.forward.dispatch``, ``ge.transfer.fetch``,
+        ``ge.transfer.gather``."""
         t_admit = batch[0].timing.admit
         # 1. one batched decide over the cycle's unique topologies
         by_topo: dict[str, list[_Entry]] = {}
         for e in batch:
             by_topo.setdefault(e.topo_key(), []).append(e)
         topos = list(by_topo)
+        span.set_metadata(topologies=len(topos))
         decided = dict(zip(topos, self.engine.decide_entries(
             [by_topo[t][0].req.state for t in topos])))
         for t in topos:
@@ -629,6 +639,7 @@ class StreamingFrontend:
             gk = self.engine.entry_bucket(pe) if self.cross_topology \
                 else pe.key
             groups.setdefault(gk, []).append(e)
+        span.set_metadata(groups=len(groups))
         # 3. dispatch every group before fetching any output
         inflight = []
         for members in groups.values():
@@ -639,17 +650,17 @@ class StreamingFrontend:
             if len({pe.key for pe in entries}) == 1:
                 plan = entries[0].plan
                 if bsz == 1:
-                    out = entries[0].forward(
-                        plan.scatter(np.asarray(xs[0], np.float32)),
-                        self.engine.params)
-                    fetch = (lambda o=out, p=plan:
-                             [p.gather(np.asarray(o))])
+                    fwd = entries[0].forward
+                    with TraceAnnotation("ge.transfer.scatter", requests=1):
+                        x_blocks = plan.scatter(np.asarray(xs[0], np.float32))
+                    gather = (lambda h, p=plan: [p.gather(h)])
                 else:
                     fwd = self.engine.batched_forward(entries[0])
-                    out = fwd(plan.scatter_batch(xs, pad_to=pad),
-                              self.engine.params)
-                    fetch = (lambda o=out, p=plan, b=bsz:
-                             p.gather_batch(np.asarray(o), count=b))
+                    with TraceAnnotation("ge.transfer.scatter",
+                                         requests=bsz):
+                        x_blocks = plan.scatter_batch(xs, pad_to=pad)
+                    gather = (lambda h, p=plan, b=bsz:
+                              p.gather_batch(h, count=b))
                 cross = False
             else:
                 # pad the member list to the batch bucket by repeating the
@@ -657,18 +668,24 @@ class StreamingFrontend:
                 # dropped by count=bsz), so compile counts stay bounded
                 padded = entries + [entries[-1]] * (pad - bsz)
                 plans, fwd = self.engine.cross_batched_forward(padded)
-                out = fwd(scatter_multi(plans, xs, pad_to=pad),
-                          self.engine.params)
-                fetch = (lambda o=out, ps=plans, b=bsz:
-                         gather_multi(ps, np.asarray(o), count=b))
+                with TraceAnnotation("ge.transfer.scatter", requests=bsz):
+                    x_blocks = scatter_multi(plans, xs, pad_to=pad)
+                gather = (lambda h, ps=plans, b=bsz:
+                          gather_multi(ps, h, count=b))
                 cross = True
-            inflight.append((members, fetch, cross))
+            with TraceAnnotation("ge.forward.dispatch", requests=bsz,
+                                 cross=int(cross)):
+                out = fwd(x_blocks, self.engine.params)
+            inflight.append((members, out, gather, cross))
         t_dispatch = self.clock.now()
         all_results: list[StreamResult] = []
         with self._lock:
-            for members, fetch, cross in inflight:
-                outputs = fetch()           # blocks on this group's fetch
+            for members, out, gather, cross in inflight:
                 bsz = len(members)
+                with TraceAnnotation("ge.transfer.fetch", requests=bsz):
+                    host = np.asarray(out)   # blocks on this group's fetch
+                with TraceAnnotation("ge.transfer.gather"):
+                    outputs = gather(host)
                 self.stats.batches += 1
                 if bsz >= 2:
                     self.stats.batched_requests += bsz
@@ -680,6 +697,7 @@ class StreamingFrontend:
                     decision, pe, hit = decided[e.topo_key()]
                     e.timing.dispatch = t_dispatch
                     e.timing.done = t_done
+                    e.timing.cycle = self._cycle
                     if e.migrations:
                         self.stats.migrated_served += 1
                     self.timings.append(e.timing)
@@ -698,7 +716,8 @@ class StreamingFrontend:
                                            (t_done - t_decided) / bsz)
             self.stats.admitted += bsz
             self.stats.served += bsz
-            self.cycles.record(bsz, t_dispatch - t_admit)
+            self.cycles.record(self._cycle, bsz, t_decided - t_admit,
+                               t_dispatch - t_decided, t_done - t_dispatch)
         return all_results
 
     # -- open-loop workload driver -------------------------------------------
@@ -756,7 +775,8 @@ class StreamingFrontend:
         results: list[StreamResult] = []
         while not (done.is_set() and not len(self.queue)):
             if not len(self.queue):
-                self.clock.sleep(idle_wait)
+                with TraceAnnotation("ge.frontend.idle"):
+                    self.clock.sleep(idle_wait)
                 continue
             results.extend(self.pump())
         producer.join()

@@ -11,6 +11,10 @@ latencies the SLO accounting is built on:
     forward    = done − dispatch      (device compute + output fetch)
     total      = done − arrival       (the end-to-end request latency)
 
+Each timing also records the ``cycle`` (the front-end's pump number) that
+served it, so a slow request joins its cycle's ``ge.frontend.cycle`` span
+in a profiler trace and its :class:`CycleTelemetry` record.
+
 The tick clock is injectable: :class:`MonotonicClock` (the default) reads
 ``time.perf_counter`` so ticks are wall-clock seconds; :class:`ManualClock`
 is a deterministic logical clock for tests and simulated workloads — the
@@ -25,12 +29,14 @@ inverse mean latency).
 """
 from __future__ import annotations
 
+import heapq
 import time
 from dataclasses import dataclass
 
 import numpy as np
 
 PERCENTILES = (50, 95, 99)
+SLOWEST_KEPT = 8                 # slowest cycles CycleTelemetry keeps whole
 
 
 class MonotonicClock:
@@ -67,11 +73,13 @@ class ManualClock:
 
 @dataclass
 class RequestTiming:
-    """The four tick stamps of one served request (−1 = not reached)."""
+    """The four tick stamps of one served request (−1 = not reached) and
+    the scheduling cycle that served it."""
     arrival: float
     admit: float = -1.0
     dispatch: float = -1.0
     done: float = -1.0
+    cycle: int = -1
 
     @property
     def queue_wait(self) -> float:
@@ -107,23 +115,46 @@ def percentiles(values, pcts=PERCENTILES) -> dict[str, float]:
 
 
 class CycleTelemetry:
-    """Decide-stage telemetry, one sample per scheduling cycle: the batch
-    size the cycle's single (vmapped) control dispatch covered, and the
-    decide-phase latency (admit→dispatch ticks) of that cycle.
+    """Per-cycle telemetry, one sample per non-empty scheduling cycle: the
+    batch size the cycle's single (vmapped) control dispatch covered, and
+    the cycle's three stages on the tick clock:
 
-    ``as_dict`` emits the per-cycle batch-size histogram plus decide
-    p50/p95 — the numbers that show the batched controller amortizing
-    (cycle batch sizes ≫ 1 while decide-per-request falls). Deterministic
-    under :class:`ManualClock`; the front-end records one sample per
-    non-empty ``pump`` cycle."""
+        decide   = decided − admit     (control decision + plan lookups)
+        dispatch = dispatched − decided (scatter + every group's forward
+                                         dispatch)
+        fetch    = done − dispatched   (blocking output fetches + gathers)
+
+    The :data:`SLOWEST_KEPT` slowest cycles (by the sum of their stages)
+    are kept whole with their cycle number, so a stall names its stage in
+    any run, traced or not.
+
+    ``as_dict`` emits the per-cycle batch-size histogram, p50/p95 of each
+    stage and the slowest cycles — the numbers that show the batched
+    controller amortizing (cycle batch sizes ≫ 1 while decide-per-request
+    falls). Deterministic under :class:`ManualClock`."""
 
     def __init__(self):
         self.batch_sizes: list[int] = []
         self.decide_ticks: list[float] = []
+        self.dispatch_ticks: list[float] = []
+        self.fetch_ticks: list[float] = []
+        self._slowest: list[tuple[float, int, dict]] = []   # min-heap
 
-    def record(self, batch_size: int, decide: float) -> None:
+    def record(self, cycle: int, batch_size: int, decide: float,
+               dispatch: float, fetch: float) -> None:
         self.batch_sizes.append(int(batch_size))
         self.decide_ticks.append(float(decide))
+        self.dispatch_ticks.append(float(dispatch))
+        self.fetch_ticks.append(float(fetch))
+        total = float(decide) + float(dispatch) + float(fetch)
+        rec = {"cycle": int(cycle), "batch": int(batch_size),
+               "decide": float(decide), "dispatch": float(dispatch),
+               "fetch": float(fetch), "total": total}
+        item = (total, len(self.batch_sizes), rec)   # index breaks ties
+        if len(self._slowest) < SLOWEST_KEPT:
+            heapq.heappush(self._slowest, item)
+        elif total > self._slowest[0][0]:
+            heapq.heapreplace(self._slowest, item)
 
     def histogram(self) -> dict[int, int]:
         out: dict[int, int] = {}
@@ -131,8 +162,14 @@ class CycleTelemetry:
             out[b] = out.get(b, 0) + 1
         return dict(sorted(out.items()))
 
+    def slowest(self) -> list[dict]:
+        """The slowest cycles' records, slowest first."""
+        return [rec for _, _, rec in sorted(self._slowest, reverse=True)]
+
     def as_dict(self) -> dict:
         dec = percentiles(self.decide_ticks)
+        dis = percentiles(self.dispatch_ticks)
+        fet = percentiles(self.fetch_ticks)
         per_req = [t / b for t, b in
                    zip(self.decide_ticks, self.batch_sizes)]
         return {"cycles": len(self.batch_sizes),
@@ -141,7 +178,10 @@ class CycleTelemetry:
                 "batch_mean": (float(np.mean(self.batch_sizes))
                                if self.batch_sizes else 0.0),
                 "decide": {"p50": dec["p50"], "p95": dec["p95"]},
-                "decide_per_request": percentiles(per_req)}
+                "decide_per_request": percentiles(per_req),
+                "dispatch": {"p50": dis["p50"], "p95": dis["p95"]},
+                "fetch": {"p50": fet["p50"], "p95": fet["p95"]},
+                "slowest": self.slowest()}
 
 
 def summarize(timings: list[RequestTiming]) -> dict:

@@ -377,8 +377,11 @@ def test_lyapunov_weighted_tenant_admits_more_under_contention():
 
 def test_cycle_telemetry_histogram_and_decide_percentiles():
     t = CycleTelemetry()
-    for b, d in ((4, 0.2), (4, 0.4), (2, 0.1), (1, 0.3)):
-        t.record(b, d)
+    for c, (b, d, disp, f) in enumerate(((4, 0.2, 0.01, 0.05),
+                                         (4, 0.4, 0.03, 0.01),
+                                         (2, 0.1, 0.02, 0.5),
+                                         (1, 0.3, 0.04, 0.02))):
+        t.record(10 + c, b, d, disp, f)
     d = t.as_dict()
     assert d["cycles"] == 4
     assert d["batch_hist"] == {"1": 1, "2": 1, "4": 2}
@@ -387,6 +390,27 @@ def test_cycle_telemetry_histogram_and_decide_percentiles():
     assert d["decide"]["p95"] == pytest.approx(
         float(np.percentile([0.2, 0.4, 0.1, 0.3], 95)))
     assert d["decide_per_request"]["max"] == pytest.approx(0.3)
+    # the other two stages, and every cycle kept whole, slowest first
+    assert d["dispatch"]["p50"] == pytest.approx(0.025)
+    assert d["fetch"]["p95"] == pytest.approx(
+        float(np.percentile([0.05, 0.01, 0.5, 0.02], 95)))
+    assert [s["cycle"] for s in d["slowest"]] == [12, 11, 13, 10]
+    assert d["slowest"][0] == {"cycle": 12, "batch": 2, "decide": 0.1,
+                               "dispatch": 0.02, "fetch": 0.5,
+                               "total": pytest.approx(0.62)}
+
+
+def test_cycle_telemetry_keeps_only_the_slowest_cycles():
+    from repro.serve.metrics import SLOWEST_KEPT
+    t = CycleTelemetry()
+    totals = [0.3, 0.1, 0.9, 0.5, 0.2, 0.8, 0.7, 0.4, 0.6, 1.0, 0.05, 0.95]
+    for c, total in enumerate(totals):
+        t.record(c, 1, total, 0.0, 0.0)
+    slow = t.as_dict()["slowest"]
+    assert len(slow) == SLOWEST_KEPT == 8
+    assert [s["total"] for s in slow] == sorted(totals, reverse=True)[:8]
+    assert slow[0]["cycle"] == totals.index(1.0)
+    assert t.as_dict()["cycles"] == len(totals)
 
 
 def test_frontend_records_cycle_telemetry_under_manual_clock():
@@ -408,6 +432,18 @@ def test_frontend_records_cycle_telemetry_under_manual_clock():
     assert d["decide"]["p50"] == pytest.approx(d["decide"]["p95"])
     assert d["decide"]["p50"] > 0
     assert fe.stats_dict()["cycles"]["cycles"] == 2
+    # decide (admit → decided) no longer counts the dispatch stage; each
+    # served request's timing names the cycle whose record holds its split
+    assert d["decide"]["p50"] == pytest.approx(0.01)
+    assert d["dispatch"]["p50"] == pytest.approx(0.01)
+    assert d["fetch"]["p50"] > 0
+    slow = {s["cycle"]: s for s in d["slowest"]}
+    assert sorted(slow) == [0, 1]
+    for s in slow.values():
+        assert s["total"] == pytest.approx(s["decide"] + s["dispatch"]
+                                           + s["fetch"])
+    assert {slow[c]["batch"] for c in slow} == {2, 4}
+    assert [t.cycle for t in fe.timings] == [0] * 4 + [1] * 2
 
 
 # -- concurrent intake --------------------------------------------------------
